@@ -12,7 +12,10 @@ exact-attention algorithm is re-blocked so the MXU sees 128-aligned
             dQ, dK, dV.
 
 Layouts: q (B, H, Sq, D); k, v (B, KVH, Skv, D); GQA maps q-head h to kv-head
-h // (H // KVH) inside the BlockSpec index maps.
+h // (H // KVH) inside the BlockSpec index maps.  Per-row statistics (the
+LSE, the backward's delta and the (m, l) scratch) are (.., Sq, 1) columns:
+Mosaic tiles the last two block dims by (8, 128) or takes them whole, so a
+trailing singleton lane axis is what lets a (block_q,) row slice compile.
 """
 from __future__ import annotations
 
@@ -23,10 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def _pos(i, block, n, offset=0):
-    return offset + i * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
 
 
 def _mask_block(iq, ik, *, block_q, block_k, causal, window, q_offset, kv_len):
@@ -66,18 +65,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                        kv_len=kv_len)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_scr[...]                            # (BQ, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot(p, v)
+    p = jnp.exp(s - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(p, v)
     m_scr[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _out():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_scr[...] + jnp.log(denom)).astype(lse_ref.dtype)
 
 
@@ -96,7 +95,7 @@ def flash_fwd(q, k, v, *, scale, causal, window, q_offset, kv_len,
         n_kv=nk)
     out_shape = [
         jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+        jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         kernel,
@@ -108,11 +107,11 @@ def flash_fwd(q, k, v, *, scale, causal, window, q_offset, kv_len,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik: (b_, h_, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         out_shape=out_shape,
@@ -137,17 +136,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)
-    delta = delta_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0].astype(jnp.float32)        # (BQ, 1)
+    delta = delta_ref[0, 0].astype(jnp.float32)    # (BQ, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     mask = _mask_block(pl.program_id(2), ik, block_q=block_q, block_k=block_k,
                        causal=causal, window=window, q_offset=q_offset,
                        kv_len=kv_len)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_scr[...] += jax.lax.dot(ds, k)
 
     @pl.when(ik == n_kv - 1)
@@ -178,10 +177,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        causal=causal, window=window, q_offset=q_offset,
                        kv_len=kv_len)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                   # (BQ, BK)
+    p = jnp.exp(s - lse)                            # (BQ, BK)
     dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
 
     @pl.when(iq == n_q - 1)
@@ -196,7 +195,8 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, window, q_offset,
     kvh, skv = k.shape[1], k.shape[2]
     g = h // kvh
     nq, nk = sq // block_q, skv // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -208,8 +208,8 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, window, q_offset,
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, iq, ik: (b_, h_ // g, ik, 0)),
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik: (b_, h_, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, iq, ik: (b_, h_, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
@@ -229,8 +229,8 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, window, q_offset,
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ik, iq: (b_, h_ // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ik, iq: (b_, h_ // g, ik, 0)),
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, ik, iq: (b_, h_, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, ik, iq: (b_, h_, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, ik, iq: (b_, h_, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, ik, iq: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, ik, iq: (b_, h_, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ik, iq: (b_, h_, ik, 0)),

@@ -281,7 +281,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     n_dev = mesh.devices.size
     tcfg = cell_train_config(cfg, shape, overrides)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             fn, args = build_train(cfg, tcfg, shape, mesh)
         elif shape.kind == "prefill":
@@ -294,8 +294,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0]
         coll = parse_collectives(compiled.as_text())
 
     rec = {

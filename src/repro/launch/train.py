@@ -36,6 +36,7 @@ from repro.checkpoint.store import (checkpoint_meta, is_adapter_checkpoint,
 from repro.core.energy import EnergyGovernor, SimulatedBattery
 from repro.core.step import (init_adapter_state, init_state, make_grad_step,
                              make_stream_step, make_train_step)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.offload.state import (LAYER_LAYOUT, LayerStreamedState,
                                  OffloadedTrainState, offload_dir_for)
@@ -516,6 +517,7 @@ def main():
         ap.error(f"--attention {args.attention!r} is not an attention impl "
                  "(choose from naive, streaming, ref, flash)")
 
+    print(f"[compile cache] {enable_compile_cache()}")
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     tcfg = TrainConfig(
         global_batch=args.batch, seq_len=args.seq,

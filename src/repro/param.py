@@ -24,15 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _flatten_with_path(tree, is_leaf=None):
-    # jax.tree.flatten_with_path only exists on newer jax; fall back to
-    # jax.tree_util on the pinned 0.4.x
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree, is_leaf=is_leaf)
-
-
 class ParamSpec(NamedTuple):
     shape: Tuple[int, ...]
     dtype: Any
@@ -78,7 +69,7 @@ def _init_leaf(key, s: ParamSpec):
 
 def init_params(rng, specs, dtype=None):
     """Materialize parameters.  Deterministic per-leaf fold of the path hash."""
-    leaves, treedef = _flatten_with_path(specs, is_leaf=is_spec)
+    leaves, treedef = jax.tree.flatten_with_path(specs, is_leaf=is_spec)
     out = []
     for path, s in leaves:
         path_str = "/".join(str(p) for p in path)
@@ -113,7 +104,7 @@ def cast_tree(tree, dtype):
 
 def flatten_names(tree, is_leaf=None):
     """[(dotted.name, leaf)] — used for checkpoint manifests and LoRA targeting."""
-    leaves, _ = _flatten_with_path(tree, is_leaf=is_leaf)
+    leaves, _ = jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
     out = []
     for path, leaf in leaves:
         parts = []

@@ -168,30 +168,21 @@ def shardings_for_specs(specs, mesh: Mesh, preset: str):
     return tree_map_specs(one, specs)
 
 
-def sharding_for_axes(axes, mesh: Mesh, preset: str) -> NamedSharding:
+def sharding_for_axes(axes, mesh, preset: str) -> NamedSharding:
     rules = PRESETS[preset]
     return NamedSharding(mesh, resolve_spec(tuple(axes), rules,
                                             tuple(mesh.axis_names)))
 
 
-def constrain(x, axes, mesh: Mesh = None, preset: str = "fsdp_tp"):
-    """with_sharding_constraint by logical activation axes.  Inside jit the
-    mesh comes from the surrounding context (mesh context manager)."""
-    if mesh is None:
-        try:
-            mesh = _current_mesh()
-        except Exception:
-            return x
-    if mesh is None or mesh.empty:
+def constrain(x, axes, preset: str = "fsdp_tp"):
+    """with_sharding_constraint by logical activation axes.  The mesh is the
+    one set around the trace with ``jax.set_mesh``; with none set there is
+    nothing to constrain to and ``x`` passes through."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(
         x, sharding_for_axes(axes, mesh, preset))
-
-
-def _current_mesh():
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
 
 
 def batch_sharding(mesh: Mesh, ndim: int, preset: str = "fsdp_tp"):

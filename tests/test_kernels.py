@@ -19,6 +19,21 @@ def _qkv(b, h, kvh, sq, skv, d, dtype):
     return q.astype(dtype), k.astype(dtype), v.astype(dtype)
 
 
+def _lse_ref(q, k, *, causal, window, q_offset):
+    """(B, H, Sq, 1) row log-sum-exp of the masked scaled scores."""
+    b, h, sq, d = q.shape
+    kf = jnp.repeat(k.astype(jnp.float32), h // k.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhsd->bhqs", q.astype(jnp.float32), kf) * d ** -0.5
+    q_pos = q_offset + jnp.arange(sq)[:, None]
+    k_pos = jnp.arange(k.shape[2])[None, :]
+    m = jnp.ones((sq, k.shape[2]), bool)
+    if causal:
+        m = m & (q_pos >= k_pos)
+    if window > 0:
+        m = m & (q_pos - k_pos < window)
+    return jax.nn.logsumexp(jnp.where(m, s, -1e30), axis=-1, keepdims=True)
+
+
 FWD_CASES = [
     # b, h, kvh, sq, skv, d, causal, window, bq, bk
     (1, 1, 1, 8, 8, 4, True, 0, 4, 4),
@@ -35,14 +50,19 @@ def test_flash_fwd_sweep(case, dtype):
     b, h, kvh, sq, skv, d, causal, window, bq, bk = case
     q, k, v = _qkv(b, h, kvh, sq, skv, d, dtype)
     q_off = skv - sq if causal else 0
-    o, _ = flash_fwd(q, k, v, scale=d ** -0.5, causal=causal, window=window,
-                     q_offset=q_off, kv_len=skv, block_q=bq, block_k=bk,
-                     interpret=True)
+    o, lse = flash_fwd(q, k, v, scale=d ** -0.5, causal=causal,
+                       window=window, q_offset=q_off, kv_len=skv, block_q=bq,
+                       block_k=bk, interpret=True)
     ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                         v.astype(jnp.float32), causal=causal, window=window,
                         q_offset=q_off)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(o.astype(jnp.float32), ref, rtol=tol, atol=tol)
+    # the LSE is a trailing-singleton column, the layout Mosaic accepts
+    assert lse.shape == (b, h, sq, 1)
+    np.testing.assert_allclose(
+        lse, _lse_ref(q, k, causal=causal, window=window, q_offset=q_off),
+        rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("case", FWD_CASES[:3])

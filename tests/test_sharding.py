@@ -105,8 +105,9 @@ for _ in range(2):
     s_ref, m_ref = step(s_ref, batch)
 
 # 8-device (2 data x 4 model) SPMD
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-with mesh:
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     state2 = init_state(jax.random.PRNGKey(0), cfg, tcfg)
     from repro.core.step import state_specs
     sspecs = state_specs(cfg, tcfg)
@@ -137,8 +138,9 @@ def test_spmd_matches_single_device(tmp_path):
     script = _MULTIDEV_SCRIPT.replace("__SRC__", repr(os.path.abspath(src)))
     p = tmp_path / "spmd_check.py"
     p.write_text(script)
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    # the child's 8 devices are virtual CPU devices: pin the platform, or on
+    # a host with libtpu installed the child reaches for the TPU instead
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, str(p)], capture_output=True,
                          text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
