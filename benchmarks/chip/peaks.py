@@ -1,0 +1,23 @@
+"""Published peaks of each accelerator, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/v5e):
+197 TFLOP/s in bfloat16, 393 TOP/s in int8, 16 GB of HBM at 819 GB/s per
+chip.  A kind that is not in the table is an error: a share of an unknown
+peak is no number.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9,
+                    "source": "cloud.google.com/tpu/docs/v5e"},
+}
+PEAKS["TPU v5e"] = PEAKS["TPU v5 lite"]
+
+
+def peak(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
