@@ -17,6 +17,7 @@
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import shutil
@@ -214,6 +215,11 @@ def restore_offload(directory: str, work_dir: str, like_params,
     return ostate, step
 
 
+# a save's stall on the training thread, as a span in a profiler trace
+_checkpoint_span = functools.partial(jax.profiler.annotate_function,
+                                     name="train.checkpoint")
+
+
 class CheckpointStore:
     """Async wrapper with SIGTERM-safe flush (preemption tolerance)."""
 
@@ -240,6 +246,7 @@ class CheckpointStore:
             raise RuntimeError(
                 "async checkpoint write failed") from err
 
+    @_checkpoint_span
     def save_async(self, state, step: int, extra_meta=None):
         self.wait()
         host_state = jax.device_get(state)  # snapshot before returning
@@ -255,11 +262,13 @@ class CheckpointStore:
         self._thread = threading.Thread(target=_write, daemon=False)
         self._thread.start()
 
+    @_checkpoint_span
     def save_sync(self, state, step: int, extra_meta=None):
         self.wait()
         return save(state, self.directory, step, keep=self.keep,
                     extra_meta=extra_meta)
 
+    @_checkpoint_span
     def save_offload(self, ostate, step: int):
         """Zero-copy (hardlink) snapshot of an OffloadedTrainState — cheap
         enough that no async thread is needed."""

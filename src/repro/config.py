@@ -220,6 +220,10 @@ class TrainConfig:
     checkpoint_dir: str = ""
     keep_checkpoints: int = 3
 
+    # --- profiling ---
+    profile_dir: str = ""              # "" -> off; else a jax.profiler trace
+    profile_steps: Tuple[int, int] = (0, 0)  # of steps first..last (inclusive)
+
     @property
     def micro_batch(self) -> int:
         assert self.global_batch % self.microbatches == 0

@@ -110,13 +110,15 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig) -> Callable:
         trainable = state["lora"] if lora_mode else state["params"]
         loss, metrics, grads = value_and_grad_accumulated(
             loss_of, trainable, batch, tcfg.microbatches, reduce_dtype)
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = lr_schedule(state["step"], base_lr=tcfg.learning_rate,
-                         warmup_steps=tcfg.warmup_steps,
-                         total_steps=tcfg.total_steps, kind=tcfg.schedule)
-        new_trainable, new_opt = adamw_update(
-            grads, state["opt"], trainable, lr=lr, beta1=tcfg.beta1,
-            beta2=tcfg.beta2, eps=tcfg.eps, weight_decay=tcfg.weight_decay)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = lr_schedule(state["step"], base_lr=tcfg.learning_rate,
+                             warmup_steps=tcfg.warmup_steps,
+                             total_steps=tcfg.total_steps, kind=tcfg.schedule)
+            new_trainable, new_opt = adamw_update(
+                grads, state["opt"], trainable, lr=lr, beta1=tcfg.beta1,
+                beta2=tcfg.beta2, eps=tcfg.eps,
+                weight_decay=tcfg.weight_decay)
         new_state = dict(state)
         if lora_mode:
             new_state["lora"] = new_trainable
@@ -157,7 +159,8 @@ def make_grad_step(cfg: ModelConfig, tcfg: TrainConfig) -> Callable:
 
         loss, metrics, grads = value_and_grad_accumulated(
             loss_of, params, batch, tcfg.microbatches, reduce_dtype)
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
         metrics = dict(metrics)
         metrics["grad_norm"] = gnorm
         return loss, metrics, grads

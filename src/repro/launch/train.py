@@ -486,7 +486,21 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--energy", action="store_true",
                     help="enable the K/mu/rho governor with a simulated battery")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "here (open it with TensorBoard's profile plugin "
+                         "or xprof)")
+    ap.add_argument("--profile-steps", default="2:4", metavar="A:B",
+                    help="the steps A to B (inclusive) that --profile-dir "
+                         "traces; the first steps compile, so start later")
     args = ap.parse_args()
+    try:
+        first, last = (int(v) for v in args.profile_steps.split(":"))
+    except ValueError:
+        first = last = -1
+    if not 0 <= first <= last:
+        ap.error(f"--profile-steps {args.profile_steps!r} is not A:B with "
+                 "0 <= A <= B")
     # fail at parse time, not deep inside the first step's split_batch
     if args.microbatches < 1:
         ap.error(f"--microbatches must be >= 1, got {args.microbatches}")
@@ -518,6 +532,12 @@ def main():
                  "(choose from naive, streaming, ref, flash)")
 
     print(f"[compile cache] {enable_compile_cache()}")
+    if args.profile_dir:
+        # a trace names device time by the scopes in op metadata, which JAX
+        # leaves out of the compile cache's key: key by it, or a cached
+        # executable could carry another version's names
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     tcfg = TrainConfig(
         global_batch=args.batch, seq_len=args.seq,
@@ -542,7 +562,8 @@ def main():
         base_quant=args.base_quant,
         offload_activations=args.offload_activations,
         activation_codec=args.activation_codec,
-        offload_io=args.offload_io)
+        offload_io=args.offload_io,
+        profile_dir=args.profile_dir, profile_steps=(first, last))
     governor = None
     if args.energy:
         governor = EnergyGovernor(monitor=SimulatedBattery(

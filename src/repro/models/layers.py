@@ -94,7 +94,9 @@ def embed_specs(vocab: int, d_model: int, tie: bool, padded_vocab: int = 0):
 
 
 def embed_tokens(p, tokens, compute_dtype):
-    return p["tok"].astype(compute_dtype)[tokens]
+    # the table is shared with a tied unembedding: both uses are one layer
+    with jax.named_scope("lm_head"):
+        return p["tok"].astype(compute_dtype)[tokens]
 
 
 def unembed(p, x, tie: bool, softcap: float = 0.0, true_vocab: int = 0):

@@ -154,13 +154,18 @@ def forward(params, batch, cfg: ModelConfig, tcfg: TrainConfig):
             layer = jax.tree.map(lambda a: a[i], xs)
             (x, aux), _ = body((x, aux), layer)
 
-    if cfg.n_meta_tokens > 0:
-        x = x[:, cfg.n_meta_tokens:]
-    x = L.apply_norm(params["ln_f"], x, cfg.norm_variant)
-    logits = L.unembed(params["embed"], x.astype(jnp.float32),
-                       cfg.tie_embeddings, cfg.logit_softcap,
-                       cfg.vocab_size)
-    return logits, aux / max(cfg.n_layers, 1)
+    return head_logits(params, x, cfg), aux / max(cfg.n_layers, 1)
+
+
+def head_logits(params, x, cfg: ModelConfig):
+    """The final norm and the unembedding of the last block's output."""
+    with jax.named_scope("lm_head"):
+        if cfg.n_meta_tokens > 0:
+            x = x[:, cfg.n_meta_tokens:]
+        x = L.apply_norm(params["ln_f"], x, cfg.norm_variant)
+        return L.unembed(params["embed"], x.astype(jnp.float32),
+                         cfg.tie_embeddings, cfg.logit_softcap,
+                         cfg.vocab_size)
 
 
 def loss_fn(params, batch, cfg: ModelConfig, tcfg: TrainConfig):
@@ -272,12 +277,7 @@ def make_layer_program(cfg: ModelConfig, tcfg: TrainConfig) -> LayerProgram:
     block_fn = maybe_remat(block_fn, tcfg.remat_policy)
 
     def head_fn(head, x, batch, aux_sum):
-        if cfg.n_meta_tokens > 0:
-            x = x[:, cfg.n_meta_tokens:]
-        x = L.apply_norm(head["ln_f"], x, cfg.norm_variant)
-        logits = L.unembed(head["embed"], x.astype(jnp.float32),
-                           cfg.tie_embeddings, cfg.logit_softcap,
-                           cfg.vocab_size)
+        logits = head_logits(head, x, cfg)
         loss, metrics = T.cross_entropy(logits, batch["labels"])
         aux = aux_sum / max(cfg.n_layers, 1)
         metrics["aux_loss"] = aux

@@ -202,30 +202,36 @@ def apply_attention(p, x, cfg: ModelConfig, tcfg: TrainConfig, *,
 
 def apply_block(p, x, cfg, tcfg, *, positions, window, kv_cache=None,
                 cache_index=None, cache_mode="update"):
-    h, cache = apply_attention(
-        p["attn"], L.apply_norm(p["ln1"], x, cfg.norm_variant), cfg, tcfg,
-        positions=positions, window=window, kv_cache=kv_cache,
-        cache_index=cache_index, cache_mode=cache_mode)
+    # the scope names are what a device trace attributes time by; remat
+    # recompute and the backward pass inherit them
+    with jax.named_scope("attention"):
+        h, cache = apply_attention(
+            p["attn"], L.apply_norm(p["ln1"], x, cfg.norm_variant), cfg, tcfg,
+            positions=positions, window=window, kv_cache=kv_cache,
+            cache_index=cache_index, cache_mode=cache_mode)
     x = x + h
-    x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x, cfg.norm_variant),
+    with jax.named_scope("mlp"):
+        h = L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x, cfg.norm_variant),
                         cfg.mlp_variant)
+    x = x + h
     x = constrain(x, ("batch", "seq", "act_embed"), preset=tcfg.shard_preset)
     return x, cache
 
 
 def cross_entropy(logits, labels):
     """Mean token NLL over labels >= 0; returns (loss, metrics)."""
-    mask = (labels >= 0).astype(jnp.float32)
-    safe = jnp.maximum(labels, 0)
-    logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    gold = jnp.take_along_axis(logits.astype(jnp.float32),
-                               safe[..., None], axis=-1)[..., 0]
-    nll = (logz - gold) * mask
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = nll.sum() / denom
-    acc = (jnp.argmax(logits, -1) == labels).astype(jnp.float32) * mask
-    return loss, {"loss": loss, "ppl_log": loss,
-                  "accuracy": acc.sum() / denom, "tokens": mask.sum()}
+    with jax.named_scope("lm_head"):
+        mask = (labels >= 0).astype(jnp.float32)
+        safe = jnp.maximum(labels, 0)
+        logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        gold = jnp.take_along_axis(logits.astype(jnp.float32),
+                                   safe[..., None], axis=-1)[..., 0]
+        nll = (logz - gold) * mask
+        denom = jnp.maximum(mask.sum(), 1.0)
+        loss = nll.sum() / denom
+        acc = (jnp.argmax(logits, -1) == labels).astype(jnp.float32) * mask
+        return loss, {"loss": loss, "ppl_log": loss,
+                      "accuracy": acc.sum() / denom, "tokens": mask.sum()}
 
 
 # ----------------------------------------------------------------------------

@@ -14,6 +14,8 @@ import os
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 
 def read_rss_mb() -> float:
     try:
@@ -48,14 +50,19 @@ class MetricsObserver:
                  tokens: float = 0.0, battery: float = 1.0):
         dt = (time.perf_counter() - self._t0) if self._t0 else 0.0
         self.energy_kj += self.power_watts * dt / 1000.0
-        loss = float(metrics.get("loss", float("nan")))
+        # the step's metrics come from the device here, one read each
+        with jax.profiler.TraceAnnotation("train.end_step.pull"):
+            loss = float(metrics.get("loss", float("nan")))
+            accuracy = float(metrics.get("accuracy", float("nan")))
+            grad_norm = float(metrics.get("grad_norm", float("nan")))
+            lr = float(metrics.get("lr", float("nan")))
         row = {
             "step": step,
             "loss": loss,
             "ppl": float(math.exp(min(loss, 30.0))) if loss == loss else None,
-            "accuracy": float(metrics.get("accuracy", float("nan"))),
-            "grad_norm": float(metrics.get("grad_norm", float("nan"))),
-            "lr": float(metrics.get("lr", float("nan"))),
+            "accuracy": accuracy,
+            "grad_norm": grad_norm,
+            "lr": lr,
             "step_time_s": dt,
             "rss_mb": read_rss_mb(),
             "power_w": self.power_watts,

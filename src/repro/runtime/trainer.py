@@ -18,6 +18,7 @@ import os
 import signal
 from typing import Callable, Iterator, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.checkpoint.store import CheckpointStore, latest_step
@@ -61,6 +62,8 @@ class TrainerRuntime:
         self._preempt_signum: Optional[int] = None
         self._preempt_flush: Optional[Callable[[], None]] = None
         self._prev_sigterm = None
+        self._step_span = None      # "train.step": hand-off to end_step
+        self._profiling = False
 
     # ------------------------------------------------------------------
     # resume / fault tolerance
@@ -136,30 +139,73 @@ class TrainerRuntime:
     def steps(self, start: int) -> Iterator[Tuple[int, dict]]:
         """(step, device batch) pairs from ``start`` to total_steps, with the
         data iterator skipped ahead so resumed runs see the exact same
-        order, and the observer's step timer armed."""
+        order, and the observer's step timer armed.
+
+        Each step leaves three host spans in a profiler trace: ``train.feed``
+        (the batch built and uploaded), ``train.step`` (from the hand-off
+        to ``end_step``: the caller's dispatch and its wait for the device)
+        and ``train.end_step``.  ``tcfg.profile_steps`` (first, last) traces
+        those steps into ``tcfg.profile_dir``."""
         batches = packed_batches(self.ds, self.tcfg.global_batch,
                                  seed=self.seed, epochs=10_000)
         for _ in range(start):
             next(batches)  # deterministic data order on resume
         try:
             for step in range(start, self.tcfg.total_steps):
+                self._close_step_span()
+                self._profile_at(step)
                 if self._preempt_signum is not None:  # deferred SIGTERM
                     self._preempt_flush()
                     raise SystemExit(128 + self._preempt_signum)
-                batch = {k: jnp.asarray(v) for k, v in next(batches).items()}
+                with jax.profiler.TraceAnnotation("train.feed"):
+                    batch = {k: jnp.asarray(v)
+                             for k, v in next(batches).items()}
                 self.obs.start_step()
+                self._step_span = jax.profiler.TraceAnnotation("train.step")
+                self._step_span.__enter__()
                 yield step, batch
         finally:
             # also runs when the consuming loop dies on an exception (the
             # generator is closed), so a crashed run stays killable
+            self._close_step_span()
+            self._stop_profile()
             self.restore_sigterm()
 
+    def _close_step_span(self):
+        if self._step_span is not None:
+            self._step_span.__exit__(None, None, None)
+            self._step_span = None
+
+    def _profile_at(self, step: int):
+        """Start the profiler before the first of ``tcfg.profile_steps``,
+        stop it before the step after the last (so the checkpoint that
+        follows a step is inside its trace)."""
+        first, last = self.tcfg.profile_steps
+        if not self.tcfg.profile_dir:
+            return
+        if step == first:
+            jax.profiler.start_trace(self.tcfg.profile_dir)
+            self._profiling = True
+        elif step == last + 1:
+            self._stop_profile()
+
+    def _stop_profile(self):
+        if self._profiling:
+            jax.profiler.stop_trace()
+            self._profiling = False
+            self.log(f"[profile] steps {self.tcfg.profile_steps[0]}-"
+                     f"{self.tcfg.profile_steps[1]} traced into "
+                     f"{self.tcfg.profile_dir}")
+
     def end_step(self, step: int, metrics) -> dict:
-        row = self.obs.end_step(step, metrics, tokens=self.tokens_per_step,
-                                battery=(self.governor.monitor.fraction()
-                                         if self.governor else 1.0))
-        if self.governor is not None:
-            self.governor.after_step(step, row["step_time_s"])
+        self._close_step_span()
+        with jax.profiler.TraceAnnotation("train.end_step"):
+            row = self.obs.end_step(
+                step, metrics, tokens=self.tokens_per_step,
+                battery=(self.governor.monitor.fraction()
+                         if self.governor else 1.0))
+            if self.governor is not None:
+                self.governor.after_step(step, row["step_time_s"])
         return row
 
     def checkpoint_due(self, step: int) -> bool:
